@@ -34,6 +34,9 @@ def main() -> None:
 
     import tempfile
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
+
     from repro.engine import TuningStore
 
     from . import fig6, fig7, fig8_9, table1

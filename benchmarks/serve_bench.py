@@ -20,6 +20,7 @@ against one store and gates on the second (warm) run reporting zero probes.
 from __future__ import annotations
 
 import argparse
+import os
 import threading
 import time
 
@@ -28,6 +29,7 @@ import numpy as np
 from repro.batch import BucketPlanCache, cp_als_batched
 from repro.core import SparseTensor, cp_als
 from repro.engine import TunePolicy
+from repro.launch.cache import enable_compile_cache
 from repro.obs import (
     enable_tracing,
     get_tracer,
@@ -145,7 +147,7 @@ def matched_sequential(tensors, batched_results):
     """Per-tensor sequential `cp_als` runs using the SAME kernel the batched
     path picked for that tensor's bucket — the parity gate compares
     like-for-like (the batched kernels are vmapped versions of the
-    sequential ones, bit-exact member-wise; comparing a batched-ALTO result
+    sequential ones, equal member-wise to float tolerance; comparing a batched-ALTO result
     against sequential-COO would only measure ALTO's different summation
     order, which the sequential path exhibits identically)."""
     from repro.engine import build_engine
@@ -218,6 +220,7 @@ def main(argv=None):
                     help="enable span tracing and write the trace JSONL "
                          "here (see docs/observability.md)")
     args = ap.parse_args(argv)
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     n = 24 if args.fast else args.n
     # Closed-loop clients: each waits for its result before submitting the
     # next request, so client concurrency caps the coalesced batch size —

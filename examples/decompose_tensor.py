@@ -19,10 +19,12 @@ sharding on a CPU host.
 """
 import argparse
 import time
+from pathlib import Path
 
 from repro.core import cp_als, decide_partition, table1_tensor
 from repro.engine import (TunePolicy, backend_table, build_engine,
                           registered_backends)
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
@@ -39,6 +41,7 @@ def main():
                     help="cold-start probe budget (prior's top-K)")
     ap.add_argument("--list-backends", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache(Path(__file__).resolve().parents[1])
 
     if args.list_backends:
         print(backend_table(docs_base=None))  # terminal output: no link noise

@@ -7,12 +7,16 @@ Fig. 5 decider pick a partition, run CP-ALS with the PRISM chunked engine
 (float), the fixed-point engine (paper Alg. 2), and the Pallas TPU kernel
 (interpret mode on CPU), and compare convergence.
 """
-import jax
+from pathlib import Path
+
 import numpy as np
 
 from repro.core import (cp_als, decide_partition, random_tensor)
+from repro.launch.cache import enable_compile_cache
+
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     # A Nell-2-like synthetic tensor (see benchmarks/table1.py for the set).
     st = random_tensor((605, 460, 1440), nnz=50_000, seed=0)
     print(f"tensor: dims={st.shape} nnz={st.nnz} density={st.density:.2e}")
@@ -28,8 +32,8 @@ def main():
         ("chunked", dict(chunk_shape=plan.chunk_shape, capacity=plan.capacity)),
         ("fixed", dict(chunk_shape=plan.chunk_shape, capacity=plan.capacity,
                        fixed_preset="int7")),
-        ("pallas", dict(chunk_shape=plan.chunk_shape,
-                        capacity=min(plan.capacity, 128))),
+        # The kernel brings its own VMEM-sized chunk plan.
+        ("pallas", {}),
     ]:
         res = cp_als(st, rank, n_iters=3, engine=engine, seed=0, **kw)
         print(f"engine={engine:8s} fit={res.fit_history[-1]:+.4f} "

@@ -6,6 +6,7 @@
 import argparse
 import sys
 import time
+from pathlib import Path
 
 sys.path.insert(0, "src")
 
@@ -15,6 +16,7 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.data import SyntheticTokens
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import generate, make_ctx
 from repro.models import LM
@@ -27,6 +29,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--gen", type=int, default=12)
     args = ap.parse_args()
+    enable_compile_cache(Path(__file__).resolve().parents[1])
 
     cfg = get_smoke_config(args.arch)
     lm = LM(cfg)

@@ -65,7 +65,6 @@ CONTRACT_MODULES = (
     "src/repro/core/baselines.py",
     "src/repro/kernels/ops.py",
     "src/repro/kernels/mttkrp_kernel.py",
-    "src/repro/kernels/mttkrp_fixed_kernel.py",
     "src/repro/kernels/ref.py",
 )
 
@@ -254,9 +253,18 @@ def _build_param(spec: dict, ndim: int, mode: int) -> df.AVal:
                                               df.Dim.sym(f"S{m}"))),
                        df.Dim.sym("R")), dt)
             for m in range(ndim)])
-    if kind == "array":
+    if kind == "factors-padded-t":
+        dt = _dtype(spec.get("dtype", "float32"))
+        return df.ATuple([
+            df.AArray((df.Dim.sym("R"),
+                       df.Dim.atom(df.CeilMul(df.Dim.sym(f"I{m}"),
+                                              df.Dim.sym(f"S{m}")))), dt)
+            for m in range(ndim)])
+    if kind in ("array", "array-per-mode"):
         dt = _dtype(spec.get("dtype", "float32"))
         shape = tuple(_parse_dim(t, ndim, mode) for t in spec["shape"])
+        if kind == "array-per-mode":
+            return df.ATuple([df.AArray(shape, dt) for _ in range(ndim)])
         return df.AArray(shape, dt)
     if kind == "mode":
         return df.AConst(mode)

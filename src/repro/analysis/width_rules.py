@@ -338,8 +338,6 @@ _QFORMAT_FILE = "src/repro/core/qformat.py"
 #: one `>> (value_frac + prec_shift)` after the value multiply.
 _SHIFT_SITES = (
     ("src/repro/core/mttkrp.py", "_fixed_partials"),
-    ("src/repro/kernels/mttkrp_fixed_kernel.py", "_kernel"),
-    ("src/repro/kernels/ref.py", "mttkrp_fixed_local_ref"),
 )
 
 

@@ -7,8 +7,9 @@ member's factors are initialized from `init_factors(member.shape, rank,
 seed)` — the sequential initializer on the member's TRUE shape, zero-padded
 to the bucket dims.  Padded factor rows receive zero MTTKRP contributions,
 solve to zero, and never disturb column norms or grams, so the per-member
-results match the sequential path to float tolerance (gated at 1e-5 in
-`benchmarks/serve_bench.py`).
+results match the sequential path to float tolerance, not bit-exactly: a
+gram over zero-padded rows reduces in a different order than over the true
+rows.  `tests/test_batch.py` holds factors to an absolute 2e-5.
 
 Where the sequential driver re-decides its engine per tensor, this one
 makes ONE decision per bucket (`tune.autotune_bucket`): the first member
@@ -22,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.cpals import CPResult, init_factors
+from ..core.cpals import _HIGHEST, CPResult, init_factors
 from ..engine.tunepolicy import TunePolicy
 from ..obs.tracing import span
 from .bucketing import Bucket, bucket_tensors, pad_bucket
@@ -43,6 +44,22 @@ def _normalize_batched(f: jnp.ndarray, norm: str):
     return f / lam[:, None, :], lam
 
 
+def _gram(f: jnp.ndarray) -> jnp.ndarray:
+    """(B, I, R) → (B, R, R) FᵀF per member: the sequential driver's
+    `f.T @ f` with a batch axis, so a member whose dims need no padding gets
+    the same bits (an einsum contracting axis 1 rounds differently)."""
+    return jnp.matmul(jnp.swapaxes(f, 1, 2), f, precision=_HIGHEST)
+
+
+@jax.jit
+def _pinv_each(v: jnp.ndarray) -> jnp.ndarray:
+    """(B, R, R) → per-member pseudo-inverses, one member at a time.  A
+    TPU's batched SVD does not give a member the bits its unbatched SVD
+    gives, so a vmapped pinv would make a served answer depend on the
+    requests it was coalesced with."""
+    return jax.lax.map(jnp.linalg.pinv, v)
+
+
 def _fit_batched(norm_x2, factors, lam, mlast):
     """Batched sparse fit identity (see `repro.core.cpals.fit_value`):
     ||X - X̂||² = ||X||² - 2<X, X̂> + ||X̂||², with the <X, X̂> fast path from
@@ -50,7 +67,7 @@ def _fit_batched(norm_x2, factors, lam, mlast):
     fast path always qualifies.  Returns (B,) fits, on device."""
     had = lam[:, :, None] * lam[:, None, :]
     for f in factors:
-        had = had * jnp.einsum("bir,bis->brs", f, f)
+        had = had * _gram(f)
     norm_approx2 = jnp.sum(had, axis=(1, 2))
     inner = jnp.sum(mlast * (factors[-1] * lam[:, None, :]), axis=(1, 2))
     resid = jnp.maximum(norm_x2 - 2.0 * inner + norm_approx2, 0.0)
@@ -176,9 +193,8 @@ def _decompose_bucket(
                     for k in range(n):
                         if k == mode:
                             continue
-                        fk = factors[k]
-                        v = v * jnp.einsum("bir,bis->brs", fk, fk)
-                    a = m @ jnp.linalg.pinv(v)
+                        v = v * _gram(factors[k])
+                    a = jnp.matmul(m, _pinv_each(v), precision=_HIGHEST)
                     a, lam = _normalize_batched(a, norm)
                     factors[mode] = a
                     mlast = m

@@ -12,7 +12,7 @@ from .mttkrp import (
 )
 from .partition import PartitionPlan, decide_partition
 from .qformat import FIXED_PRESETS, Q17_15, Q5_3, Q9_7, QFormat, value_qformat
-from .sptensor import TABLE1, SparseTensor, random_tensor, table1_tensor
+from .sptensor import FROSTT, TABLE1, SparseTensor, random_tensor, table1_tensor
 
 
 def __getattr__(name):

@@ -135,7 +135,12 @@ def chunk_tensor(
     lin = np.zeros(st.nnz, dtype=np.int64)
     for m in range(n):
         lin = lin * grid[m] + chunk_coord[:, m]
-    order = np.argsort(lin, kind="stable")
+    if math.prod(grid) * max(st.nnz, 1) < 1 << 62:
+        # Stable order by chunk without the slow stable sort: break ties
+        # by position, so every key is distinct.
+        order = np.argsort(lin * st.nnz + np.arange(st.nnz))
+    else:
+        order = np.argsort(lin, kind="stable")
     lin_s = lin[order]
     coords_s = st.coords[order]
     values_s = st.values[order]

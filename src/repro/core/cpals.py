@@ -43,6 +43,10 @@ import numpy as np
 from ..obs.tracing import span
 from .sptensor import SparseTensor
 
+#: The ALS solve's matmuls run at full f32 precision: at the default, a TPU
+#: rounds f32 operands to bf16 on the MXU.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "CPResult",
     "cp_als",
@@ -122,7 +126,8 @@ def fit_value(st: SparseTensor, factors, lam, mlast=None, last_mode=None) -> flo
     """fit = 1 - ||X - X̂||_F / ||X||_F, using the standard sparse identity
     ||X - X̂||² = ||X||² - 2<X, X̂> + ||X̂||²."""
     norm_x2 = st.norm() ** 2
-    grams = [jnp.asarray(f).T @ jnp.asarray(f) for f in factors]
+    grams = [jnp.matmul(jnp.asarray(f).T, jnp.asarray(f), precision=_HIGHEST)
+             for f in factors]
     had = jnp.asarray(lam)[:, None] * jnp.asarray(lam)[None, :]
     for g in grams:
         had = had * g
@@ -132,7 +137,7 @@ def fit_value(st: SparseTensor, factors, lam, mlast=None, last_mode=None) -> flo
                          * jnp.asarray(lam)[None, :]))
         if mlast is not None and last_mode is not None
         else jnp.dot(reconstruct_nnz(factors, lam, jnp.asarray(st.coords)),
-                     jnp.asarray(st.values)))
+                     jnp.asarray(st.values), precision=_HIGHEST))
     # Both reductions stay on device and fuse into ONE residual readout —
     # fit is a host scalar by contract, so exactly one sync is the floor
     # (this used to read norm_approx2 and inner back separately).
@@ -311,8 +316,9 @@ def cp_als(
                             if k == mode:
                                 continue
                             fk = jnp.asarray(factors[k])
-                            v = v * (fk.T @ fk)
-                        a = m @ jnp.linalg.pinv(v)
+                            v = v * jnp.matmul(fk.T, fk, precision=_HIGHEST)
+                        a = jnp.matmul(m, jnp.linalg.pinv(v),
+                                       precision=_HIGHEST)
                         a, lam = _normalize(a, norm)
                         factors[mode] = a
                         mlast = m

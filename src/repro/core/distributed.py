@@ -30,7 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..launch.mesh import shard_map
 from .chunking import ChunkedTensor
-from .mttkrp import mttkrp_chunked
+from .blocked import mttkrp_chunked_blocked
 
 __all__ = ["distributed_mttkrp_fn", "shard_chunked", "DistributedMTTKRP"]
 
@@ -64,7 +64,7 @@ def distributed_mttkrp_fn(
     n_data = axes[data_axis]
 
     def body(factors, task_chunk, coords_rel, values):
-        local = mttkrp_chunked(
+        local = mttkrp_chunked_blocked(
             factors, task_chunk, coords_rel, values,
             mode=mode, chunk_shape=chunk_shape, out_dim=_pad_dim(out_dim, n_data),
         )

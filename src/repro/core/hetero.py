@@ -25,9 +25,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from .chunking import ChunkedTensor
-from .mttkrp import gather_factor_blocks, mttkrp_chunked
+from .blocked import mttkrp_chunked_blocked
+from .mttkrp import gather_factor_blocks
 
-__all__ = ["HeteroSplit", "split_tasks", "mttkrp_hetero", "dense_path_cost", "sparse_path_cost"]
+__all__ = ["HeteroSplit", "split_tasks", "hetero_arrays", "mttkrp_hetero",
+           "dense_path_cost", "sparse_path_cost"]
 
 
 def dense_path_cost(chunk_shape, rank: int) -> float:
@@ -127,10 +129,29 @@ def _dense_path(
         operands.append(blk)
         subs.append(f"t{letters[m]}r")
     out_sub = f"t{letters[mode]}r"
-    local = jnp.einsum(",".join(subs) + "->" + out_sub, *operands)  # (Td, S, R)
+    # HIGHEST: at default precision the MXU rounds the f32 operands to
+    # bf16, which would make this path lossy.
+    local = jnp.einsum(",".join(subs) + "->" + out_sub, *operands,
+                       precision=jax.lax.Precision.HIGHEST)  # (Td, S, R)
     out = jnp.zeros((out_dim, rank), jnp.float32)
     rows = offsets[:, mode : mode + 1] + jnp.arange(chunk_shape[mode])[None, :]
     return out.at[rows.reshape(-1)].add(local.reshape(-1, rank), mode="drop")
+
+
+def hetero_arrays(ct: ChunkedTensor, split: HeteroSplit,
+                  full: dict | None = None) -> dict:
+    """Device arrays of both paths, placed once per engine.  `full` holds
+    the whole tensor's chunked arrays (`chunked_device_arrays`); when no
+    task goes dense they are the sparse path's arrays, and nothing is
+    copied."""
+    if full is not None and not split.dense_idx.size:
+        sparse = full
+    else:
+        sparse = dict(task_chunk=jnp.asarray(ct.task_chunk[split.sparse_idx]),
+                      coords_rel=jnp.asarray(ct.coords_rel[split.sparse_idx]),
+                      values=jnp.asarray(ct.values[split.sparse_idx]))
+    return dict(dense_task_chunk=jnp.asarray(ct.task_chunk[split.dense_idx]),
+                sparse=sparse)
 
 
 def mttkrp_hetero(
@@ -141,24 +162,27 @@ def mttkrp_hetero(
     *,
     mode: int,
     out_dim: int,
+    arrays: dict,
 ):
-    """Run both paths and sum (the paper's final CPU+PIM combine)."""
+    """Run both paths and sum (the paper's final CPU+PIM combine).
+    `arrays` is `hetero_arrays(ct, split)`, placed on the device once."""
     out = jnp.zeros((out_dim, factors[0].shape[1]), jnp.float32)
     if split.dense_idx.size:
         out = out + _dense_path(
             factors,
             dense_blocks,
-            jnp.asarray(ct.task_chunk[split.dense_idx]),
+            arrays["dense_task_chunk"],
             mode=mode,
             chunk_shape=ct.chunk_shape,
             out_dim=out_dim,
         )
     if split.sparse_idx.size:
-        out = out + mttkrp_chunked(
+        sparse = arrays["sparse"]
+        out = out + mttkrp_chunked_blocked(
             factors,
-            jnp.asarray(ct.task_chunk[split.sparse_idx]),
-            jnp.asarray(ct.coords_rel[split.sparse_idx]),
-            jnp.asarray(ct.values[split.sparse_idx]),
+            sparse["task_chunk"],
+            sparse["coords_rel"],
+            sparse["values"],
             mode=mode,
             chunk_shape=ct.chunk_shape,
             out_dim=out_dim,

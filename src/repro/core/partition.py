@@ -24,9 +24,36 @@ import numpy as np
 
 from .sptensor import SparseTensor
 
-__all__ = ["PartitionPlan", "decide_partition", "DPU_MRAM_BYTES"]
+__all__ = ["PartitionPlan", "decide_kernel_partition", "decide_partition",
+           "DPU_MRAM_BYTES"]
 
 DPU_MRAM_BYTES = 64 * 1024 * 1024  # UPMEM per-DPU MRAM; the per-PE budget knob.
+
+#: Chunk edge of the Pallas kernel's plan: a chunk's (R, S) factor block is
+#: lane-major in S, so S is a multiple of 128 (or a whole mode), and its
+#: (S, P) one-hot tiles must sit in VMEM.
+KERNEL_CHUNK = 256
+#: Task capacity P of the kernel plan, in lanes: [128, 1024].
+KERNEL_CAPACITY = (128, 1024)
+
+
+def decide_kernel_partition(shape: tuple[int, ...],
+                            nnz: int) -> tuple[tuple[int, ...], int]:
+    """(chunk_shape, capacity) for the Pallas kernel.
+
+    The MRAM-sized plan of `decide_partition` gives chunks of thousands of
+    rows and tasks of millions of nonzeros, whose one-hot tiles could never
+    fit VMEM.  Here chunks are `KERNEL_CHUNK` rows per mode (a smaller mode
+    is one whole chunk), and P is the expected population of an occupied
+    chunk plus four standard deviations, rounded up to whole 128-lane
+    vectors, so a uniform tensor rarely splits a chunk.  Skewed tensors
+    split hot chunks into several tasks (nonzero partitioning)."""
+    chunk = tuple(min(int(d), KERNEL_CHUNK) for d in shape)
+    cells = math.prod(-(-int(d) // s) for d, s in zip(shape, chunk, strict=True))
+    pop = nnz / max(1, min(cells, nnz))
+    lo, hi = KERNEL_CAPACITY
+    cap = 128 * math.ceil((pop + 4 * math.sqrt(pop)) / 128)
+    return chunk, int(min(max(cap, lo), hi))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -10,7 +10,8 @@ Each maps one of the paper's execution arms onto this host:
   chunked      PRISM chunked format, float (the "PIM" role)
   fixed        PRISM chunked + Alg.-2 fixed point (paper §IV-C)
   hetero       dense(MXU)/sparse split (paper §IV-D collaboration)
-  pallas       the Pallas TPU kernel (interpret mode on CPU hosts)
+  pallas       the Pallas TPU kernel on its own VMEM-sized chunk plan
+               (interpret mode on CPU hosts)
   distributed  shard_map over a (data, model) mesh (paper §IV-B on TPU)
 
 All chunk-based builders pull their ChunkedTensor / device arrays from the
@@ -25,8 +26,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..core import baselines, hetero, lockfree, mttkrp
+from ..core import baselines, blocked, hetero, lockfree, mttkrp
 from ..core.distributed import DistributedMTTKRP
+from ..core.partition import decide_kernel_partition
 from ..core.qformat import FIXED_PRESETS, value_qformat
 from ..launch.mesh import make_local_mesh
 from .registry import EngineContext, register_backend
@@ -43,8 +45,8 @@ def _build_ref(ctx: EngineContext):
     shape = ctx.st.shape
 
     def engine(factors, mode):
-        return mttkrp.mttkrp_coo(tuple(factors), coords, values,
-                                 mode=mode, out_dim=shape[mode])
+        return blocked.mttkrp_coo_blocked(tuple(factors), coords, values,
+                                          mode=mode, out_dim=shape[mode])
     return engine
 
 
@@ -72,7 +74,7 @@ def _build_alto(ctx: EngineContext):
     positions = at.positions
 
     def engine(factors, mode):
-        return mttkrp.mttkrp_alto(
+        return blocked.mttkrp_alto_blocked(
             tuple(factors), dev["key_words"], dev["values"],
             mode=mode, positions=positions, out_dim=shape[mode])
     return engine
@@ -90,7 +92,7 @@ def _build_csf(ctx: EngineContext):
         # builds against one tensor construct each tree exactly once.
         tree = formats.csf(st, mode)
         dev = formats.device_csf(st, mode)
-        return mttkrp.mttkrp_csf(
+        return blocked.mttkrp_csf_blocked(
             tuple(factors), dev["inner_coord"], dev["values"],
             dev["fiber_ids"], dev["fiber_coords"],
             mode=mode, inner_mode=tree.inner_mode, mid_modes=tree.mid_modes,
@@ -112,7 +114,7 @@ def _build_chunked(ctx: EngineContext):
         if nnz_pt is not None:
             m = lockfree.wave_collision_mask(dev["coords_rel"][:, :, mode], nnz_pt)
             vals = vals * m
-        return mttkrp.mttkrp_chunked(
+        return blocked.mttkrp_chunked_blocked(
             tuple(factors), dev["task_chunk"], dev["coords_rel"], vals,
             mode=mode, chunk_shape=cs, out_dim=shape[mode])
     return engine
@@ -160,30 +162,33 @@ def _build_hetero(ctx: EngineContext):
     ct = ctx.chunked()
     split = hetero.split_tasks(ct, ctx.rank, dense_fraction=ctx.dense_fraction)
     dense_blocks = jnp.asarray(hetero.densify_tasks(ct, split.dense_idx))
+    arrays = hetero.hetero_arrays(ct, split, full=ctx.device_arrays())
     shape = ctx.st.shape
 
     def engine(factors, mode):
         return hetero.mttkrp_hetero(
             tuple(factors), ct, split, dense_blocks,
-            mode=mode, out_dim=shape[mode])
+            mode=mode, out_dim=shape[mode], arrays=arrays)
     return engine
 
 
 @register_backend(
     "pallas", needs_chunking=True,
-    description="Pallas TPU kernel (interpret mode on CPU hosts)")
+    description="Pallas TPU kernel, VMEM-sized chunk plan (interpret mode on CPU hosts)")
 def _build_pallas(ctx: EngineContext):
     from ..kernels import ops as kops
-    ct = ctx.chunked()
-    dev = ctx.device_arrays()
-    cs, shape = ct.chunk_shape, ctx.st.shape
+    shape = ctx.st.shape
+    # The kernel has its own plan: the decider's MRAM-sized chunks would
+    # never fit VMEM.  An explicit chunk_shape/capacity still wins.
+    plan_cs, plan_cap = decide_kernel_partition(shape, ctx.st.nnz)
+    cs = ctx.chunk_shape or plan_cs
+    cap = ctx.capacity if ctx.chunk_shape or ctx.capacity else plan_cap
+    kt = kops.kernel_tensor(ctx.plans.chunked(ctx.st, cs, cap))
     interpret = ctx.interpret
 
     def engine(factors, mode):
-        return kops.mttkrp_pallas(
-            tuple(factors), dev["task_chunk"], dev["coords_rel"],
-            dev["values"], mode=mode, chunk_shape=cs,
-            out_dim=shape[mode], interpret=interpret)
+        return kops.mttkrp_pallas(tuple(factors), kt, mode=mode,
+                                  out_dim=shape[mode], interpret=interpret)
     return engine
 
 

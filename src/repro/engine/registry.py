@@ -246,7 +246,9 @@ class EngineContext:
     dense_fraction: float | None = None
     mesh: object | None = None      # distributed backend; None → local mesh
     reduce: str = "psum"            # distributed reduction strategy
-    interpret: bool = True          # pallas: interpret mode (CPU) vs real TPU
+    #: pallas: interpret mode.  None resolves from the JAX backend: the
+    #: interpreter only on a CPU host, the compiled kernel everywhere else.
+    interpret: bool | None = None
     plans: PlanCache = dataclasses.field(default_factory=lambda: default_plan_cache)
     #: Sparse-layout cache (repro.formats): CSF trees / ALTO linearization
     #: built once per tensor and shared across backends and autotune probes,
@@ -262,17 +264,20 @@ class EngineContext:
                 f"capacity must be >= 1 nonzero slot per chunk task (got "
                 f"{self.capacity}); pass capacity=None to let the partition "
                 "decider choose")
+        if self.interpret is None:
+            self.interpret = jax.default_backend() == "cpu"
 
     def resolve_chunking(self) -> tuple[tuple[int, ...], int | None]:
-        """Fill chunk_shape/capacity from the partition decider if unset."""
-        if self.chunk_shape is None:
-            plan = self.plans.plan(
-                self.st, self.rank,
-                mem_bytes=self.mem_bytes or 64 * 1024 * 1024)
-            self.chunk_shape = plan.chunk_shape
-            if self.capacity is None:
-                self.capacity = plan.capacity
-        return self.chunk_shape, self.capacity
+        """chunk_shape/capacity, from the partition decider where unset.
+        The context itself is left as the caller built it, so a builder
+        with its own plan (`pallas`) can tell explicit options apart."""
+        if self.chunk_shape is not None:
+            return self.chunk_shape, self.capacity
+        plan = self.plans.plan(
+            self.st, self.rank,
+            mem_bytes=self.mem_bytes or 64 * 1024 * 1024)
+        return (plan.chunk_shape,
+                self.capacity if self.capacity is not None else plan.capacity)
 
     def chunked(self):
         cs, cap = self.resolve_chunking()

@@ -117,11 +117,22 @@ def build_alto(st: SparseTensor) -> ALTOTensor:
             "planned lift — see ROADMAP)")
     positions = alto_positions(st.shape)
     key = np.zeros(st.nnz, dtype=np.uint64)
+    byte = np.arange(256)
     for m, pos in enumerate(positions):
-        c = st.coords[:, m].astype(np.uint64)
-        for b, p in enumerate(pos):
-            key |= ((c >> np.uint64(b)) & np.uint64(1)) << np.uint64(p)
-    perm = np.argsort(key, kind="stable").astype(np.int64)
+        c = st.coords[:, m]
+        # Spread one coordinate byte at a time through a 256-entry table of
+        # its bits' key positions.
+        for lo in range(0, len(pos), 8):
+            table = np.zeros(256, dtype=np.uint64)
+            for b, p in enumerate(pos[lo:lo + 8]):
+                table |= ((byte >> b) & 1).astype(np.uint64) << np.uint64(p)
+            key |= table[(c >> lo) % 256]
+    # Distinct coordinates have distinct keys, so any sort is the stable
+    # one; only duplicates need the (slower) stable sort.
+    perm = np.argsort(key)
+    if np.any(key[perm[1:]] == key[perm[:-1]]):
+        perm = np.argsort(key, kind="stable")
+    perm = perm.astype(np.int64)
     key = key[perm]
     n_words = max(1, -(-bits // 32))
     words = np.empty((st.nnz, n_words), dtype=np.uint32)

@@ -104,17 +104,33 @@ def build_csf_tree(st: SparseTensor, mode: int) -> CSFModeTree:
     """Sort the nonzeros into mode-`mode` tree order and delimit fibers."""
     root, mids, inner = csf_mode_order(st.shape, mode)
     prefix = (root, *mids)
-    # np.lexsort: last key is most significant → (root, mids..., inner).
-    keys = [st.coords[:, inner], *(st.coords[:, m] for m in reversed(prefix))]
-    perm = np.lexsort(tuple(keys)).astype(np.int64)
-    coords_s = st.coords[perm]
-
-    if st.nnz == 0:
-        new_fiber = np.zeros(0, dtype=bool)
+    order = (*prefix, inner)
+    coords = st.coords
+    if math.prod(st.shape) <= np.iinfo(np.int64).max:
+        # Row-major keys in tree order: a 1-D sort of them is lexsort's
+        # permutation.  Only duplicate coordinates need the (slower) stable
+        # sort to keep lexsort's tie order.
+        key = np.zeros(st.nnz, dtype=np.int64)
+        for m in order:
+            key = key * st.shape[m] + coords[:, m]
+        perm = np.argsort(key)
+        ordered = key[perm]
+        if np.any(ordered[1:] == ordered[:-1]):
+            perm = np.argsort(key, kind="stable")
+            ordered = key[perm]
+        fiber_key = ordered // st.shape[inner]
+        new_fiber = np.empty(st.nnz, dtype=bool)
+        new_fiber[:1] = True
+        np.not_equal(fiber_key[1:], fiber_key[:-1], out=new_fiber[1:])
+        coords_s = coords[perm]
     else:
+        # np.lexsort: last key is most significant → (root, mids..., inner).
+        keys = [coords[:, inner], *(coords[:, m] for m in reversed(prefix))]
+        perm = np.lexsort(tuple(keys)).astype(np.int64)
+        coords_s = coords[perm]
         prev = coords_s[:-1][:, list(prefix)]
         cur = coords_s[1:][:, list(prefix)]
-        new_fiber = np.concatenate([[True], (prev != cur).any(axis=1)])
+        new_fiber = np.concatenate([[True], (prev != cur).any(axis=1)])[:st.nnz]
     fiber_ids = (np.cumsum(new_fiber) - 1).astype(np.int32)
     fiber_coords = np.zeros((int(new_fiber.sum()), st.ndim), dtype=np.int32)
     if fiber_coords.shape[0]:

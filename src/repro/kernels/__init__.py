@@ -1,6 +1,6 @@
-"""Pallas TPU kernels for the PRISM spMTTKRP hot spot.
+"""Pallas TPU kernel for the PRISM spMTTKRP hot spot.
 
-`mttkrp_kernel` / `mttkrp_fixed_kernel` hold the pallas_call bodies,
-`ops` the jit'd public wrappers, `ref` the pure-jnp oracles.
+`mttkrp_kernel` holds the pallas_call body, `ops` the device layout and
+jit'd public wrapper, `ref` the pure-jnp oracle.
 """
-from .ops import mttkrp_fixed_pallas, mttkrp_pallas
+from .ops import kernel_tensor, mttkrp_pallas

@@ -1,6 +1,6 @@
-"""Pure-jnp oracles for the Pallas kernels (no pallas imports).
+"""Pure-jnp oracle for the Pallas kernel (no pallas imports).
 
-These mirror the kernels' *local* contract — per-task (T, S_mode, R) partial
+It mirrors the kernel's *local* contract — per-task (T, S_mode, R) partial
 blocks, before the global sum reduction — so allclose tests compare the
 kernel body itself, not the surrounding scatter.
 """
@@ -11,7 +11,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-__all__ = ["mttkrp_local_ref", "mttkrp_fixed_local_ref", "reduce_local"]
+__all__ = ["mttkrp_local_ref"]
 
 
 @partial(jax.jit, static_argnames=("mode", "chunk_shape"))
@@ -32,41 +32,3 @@ def mttkrp_local_ref(factors, task_chunk, coords_rel, values, *,
     local = jnp.zeros((task_chunk.shape[0], s_out, rank), jnp.float32)
     return jax.vmap(lambda l, c, p: l.at[c].add(p, mode="drop"))(
         local, coords_rel[:, :, mode], part)
-
-
-@partial(jax.jit, static_argnames=("mode", "chunk_shape", "matrix_frac",
-                                   "value_frac", "prec_shift"))
-def mttkrp_fixed_local_ref(qfactors, task_chunk, coords_rel, qvalues, *,
-                           mode: int, chunk_shape: tuple[int, ...],
-                           matrix_frac: int, value_frac: int,
-                           prec_shift: int = 0):
-    """(T, S_mode, R) int32 per-task partials, bit-exact Algorithm 2."""
-    n = len(qfactors)
-    rank = qfactors[0].shape[1]
-    offsets = task_chunk * jnp.asarray(chunk_shape, dtype=jnp.int32)
-    part = None
-    for m in range(n):
-        if m == mode:
-            continue
-        idx = offsets[:, m][:, None] + coords_rel[:, :, m]
-        idx = jnp.minimum(idx, qfactors[m].shape[0] - 1)
-        rows = qfactors[m][idx].astype(jnp.int32)
-        part = (rows if part is None
-                else jax.lax.shift_right_arithmetic(part * rows, matrix_frac))
-    part = part * qvalues[..., None].astype(jnp.int32)
-    part = jax.lax.shift_right_arithmetic(part, value_frac + prec_shift)
-    s_out = chunk_shape[mode]
-    local = jnp.zeros((task_chunk.shape[0], s_out, rank), jnp.int32)
-    return jax.vmap(lambda l, c, p: l.at[c].add(p, mode="drop"))(
-        local, coords_rel[:, :, mode], part)
-
-
-@partial(jax.jit, static_argnames=("mode", "chunk_shape", "out_dim"))
-def reduce_local(local, task_chunk, *, mode: int,
-                 chunk_shape: tuple[int, ...], out_dim: int):
-    """Global sum reduction of per-task partial blocks (paper's host step)."""
-    rank = local.shape[-1]
-    s_out = chunk_shape[mode]
-    rows = task_chunk[:, mode][:, None] * s_out + jnp.arange(s_out)[None, :]
-    out = jnp.zeros((out_dim, rank), local.dtype)
-    return out.at[rows.reshape(-1)].add(local.reshape(-1, rank), mode="drop")

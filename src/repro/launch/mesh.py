@@ -1,18 +1,11 @@
-"""Production mesh builders + JAX version-compat shims.
+"""Production mesh builders and the repo's one shard_map spelling.
 
 A FUNCTION, not a module constant — importing this module never touches jax
 device state (the dry-run sets XLA_FLAGS before first jax init).
 
-The compat layer papers over API drift between JAX releases:
-
-  * ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-    ``jax.make_mesh``) only exist on newer JAX; older releases build the
-    same Auto-typed mesh without the kwarg.
-  * ``jax.shard_map`` (with ``check_vma=``) replaced
-    ``jax.experimental.shard_map.shard_map`` (with ``check_rep=``).
-
-Everything in this repo goes through ``make_mesh_compat`` / ``shard_map``
-below instead of calling the raw jax APIs.
+``make_mesh_compat`` and ``shard_map`` keep their names so callers stay
+put; they are thin spellings of ``jax.make_mesh`` with Auto axis types and
+``jax.shard_map`` with replication checking off.
 """
 from __future__ import annotations
 
@@ -28,39 +21,17 @@ __all__ = [
 ]
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where supported, ``{}`` on older JAX."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_mesh_compat(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """``jax.make_mesh`` with Auto axis types when the installed JAX has
-    them, plain mesh otherwise (older JAX is Auto-by-default)."""
-    try:
-        return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
-    except TypeError:  # very old jax.make_mesh without axis_types kwarg
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` over this process's devices, every axis Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def shard_map(body, *, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking disabled
-    (all bodies in this repo do their own collectives)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-        return fn(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    try:
-        return fn(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        # mid-window releases expose jax.shard_map but still spell the
-        # replication-check kwarg check_rep
-        return fn(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """``jax.shard_map`` with replication checking disabled (all bodies in
+    this repo do their own collectives)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -259,7 +259,7 @@ def test_mutation_sorted_flag_drop_is_caught(tmp_path):
 def test_mutation_blockspec_mode_rotation_is_caught(tmp_path):
     ctx = _scratch_repo(tmp_path, (
         "src/repro/kernels/mttkrp_kernel.py",
-        "(chunk_shape[m], rank)", "(chunk_shape[mode], rank)"))
+        "(rank, chunk_shape[m])", "(rank, chunk_shape[mode])"))
     report = sr.contract_report(ctx)
     assert any("divide" in msg for _, _, msg in report["pallas"])
 

@@ -9,7 +9,8 @@ import pytest
 from repro.core import Q17_15, Q9_7, random_tensor, value_qformat
 from repro.core.baselines import alto_order, mttkrp_alto, mttkrp_plain_coo
 from repro.core.chunking import chunk_tensor
-from repro.core.hetero import densify_tasks, mttkrp_hetero, split_tasks
+from repro.core.hetero import (densify_tasks, hetero_arrays, mttkrp_hetero,
+                               split_tasks)
 from repro.core.mttkrp import (dequantize_output, mttkrp_chunked,
                                mttkrp_chunked_fixed, mttkrp_coo,
                                mttkrp_coo_fixed)
@@ -112,12 +113,13 @@ def test_hetero_split_paths_match():
     for frac in (0.0, 0.5, 1.0):
         split = split_tasks(ct, rank, dense_fraction=frac)
         db = jnp.asarray(densify_tasks(ct, split.dense_idx))
+        arrays = hetero_arrays(ct, split)
         for mode in range(3):
             ref = mttkrp_coo(factors, jnp.asarray(st.coords),
                              jnp.asarray(st.values), mode=mode,
                              out_dim=st.shape[mode])
             out = mttkrp_hetero(factors, ct, split, db, mode=mode,
-                                out_dim=st.shape[mode])
+                                out_dim=st.shape[mode], arrays=arrays)
             np.testing.assert_allclose(ref, out, rtol=1e-4, atol=1e-4)
 
 
@@ -127,3 +129,55 @@ def test_hetero_cost_model_split_is_valid():
     split = split_tasks(ct, 8)
     all_idx = np.sort(np.concatenate([split.dense_idx, split.sparse_idx]))
     np.testing.assert_array_equal(all_idx, np.arange(ct.num_tasks))
+
+
+@pytest.mark.parametrize("kernel", ["coo", "alto", "csf", "chunked"])
+@pytest.mark.parametrize("block", [64, 97])
+def test_blocked_kernels_match_unblocked(kernel, block):
+    """The nnz-blocked drivers sum the same kernel over blocks of `block`
+    nonzeros (97: the last block is clamped and partly masked)."""
+    from repro.core import blocked
+    from repro.formats.alto import build_alto
+    from repro.formats.csf import build_csf_tree
+    shape = (30, 20, 40)
+    st = random_tensor(shape, 600, seed=4)
+    factors = _factors(shape, 8)
+    for mode in range(3):
+        ref = mttkrp_coo(factors, jnp.asarray(st.coords),
+                         jnp.asarray(st.values), mode=mode, out_dim=shape[mode])
+        if kernel == "coo":
+            out = blocked.mttkrp_coo_blocked(
+                factors, jnp.asarray(st.coords), jnp.asarray(st.values),
+                mode=mode, out_dim=shape[mode], block=block)
+        elif kernel == "alto":
+            at = build_alto(st)
+            out = blocked.mttkrp_alto_blocked(
+                factors, jnp.asarray(at.key_words), jnp.asarray(at.values),
+                mode=mode, positions=at.positions, out_dim=shape[mode],
+                block=block)
+        elif kernel == "csf":
+            t = build_csf_tree(st, mode)
+            assert t.n_fibers > block  # fiber windows slide
+            out = blocked.mttkrp_csf_blocked(
+                factors, jnp.asarray(t.inner_coord), jnp.asarray(t.values),
+                jnp.asarray(t.fiber_ids), jnp.asarray(t.fiber_coords),
+                mode=mode, inner_mode=t.inner_mode, mid_modes=t.mid_modes,
+                out_dim=shape[mode], n_fibers=t.n_fibers, block=block)
+        else:
+            # capacity 40 < block: whole tasks per block; then one task of
+            # 600 slots > block: slices of a task per block.
+            for cap in (40, None):
+                ct = chunk_tensor(st, (16, 8, 16), capacity=cap)
+                out = blocked.mttkrp_chunked_blocked(
+                    factors, jnp.asarray(ct.task_chunk),
+                    jnp.asarray(ct.coords_rel), jnp.asarray(ct.values),
+                    mode=mode, chunk_shape=ct.chunk_shape,
+                    out_dim=shape[mode], block=block)
+                np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+            ct = chunk_tensor(st, shape, capacity=None)
+            assert ct.capacity > block
+            out = blocked.mttkrp_chunked_blocked(
+                factors, jnp.asarray(ct.task_chunk), jnp.asarray(ct.coords_rel),
+                jnp.asarray(ct.values), mode=mode, chunk_shape=ct.chunk_shape,
+                out_dim=shape[mode], block=block)
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)

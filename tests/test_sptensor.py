@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.core import random_tensor, table1_tensor
-from repro.core.sptensor import TABLE1, SparseTensor
+from repro.core.sptensor import TABLE1, SparseTensor, _dedup
 
 
 # ---------------------------------------------------------------------------
@@ -90,3 +90,18 @@ def test_permuted_rejects_non_permutations(bad, why):
     assert st.nnz == 60
     with pytest.raises(ValueError, match="permutation"):
         st.permuted(bad)
+
+
+def test_dedup_linear_key_path_is_byte_identical():
+    # Power-law draws collide often, so the duplicate-summing path is hit.
+    rng = np.random.default_rng(3)
+    shape = (40, 7, 300)
+    coords = np.stack([np.minimum(rng.zipf(1.3, size=5000) - 1, d - 1)
+                       for d in shape], axis=1).astype(np.int32)
+    values = rng.uniform(-1, 1, size=5000).astype(np.float32)
+    slow_c, slow_v = _dedup(coords, values)
+    fast_c, fast_v = _dedup(coords, values, shape)
+    assert slow_c.shape[0] < coords.shape[0]
+    assert fast_c.dtype == slow_c.dtype and fast_v.dtype == slow_v.dtype
+    assert fast_c.tobytes() == slow_c.tobytes()
+    assert fast_v.tobytes() == slow_v.tobytes()

@@ -56,3 +56,23 @@ def test_serve_generation_end_to_end(trivial_mesh):
     toks = generate(lm, params, ctx, prompts, gen=4)
     assert toks.shape == (2, 4)
     assert bool(jnp.all((toks >= 0) & (toks < cfg.vocab)))
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir(tmp_path, monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache is
+    the checkout's fixed .jax_cache."""
+    from repro.launch.cache import CACHE_ENV, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_dir is None:
+            monkeypatch.delenv(CACHE_ENV, raising=False)
+            want = str(tmp_path / ".jax_cache")
+            assert enable_compile_cache(tmp_path) == want
+            assert jax.config.jax_compilation_cache_dir == want
+        else:
+            monkeypatch.setenv(CACHE_ENV, str(tmp_path / env_dir))
+            assert enable_compile_cache(tmp_path) == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
