@@ -16,7 +16,7 @@ The supported product surface, re-exported from the subsystems:
 - `repro.serve`   — `DecomposeService`, the coalescing request loop over
                     the batched path.
 - `repro.obs`     — span tracing (`span`/`traced`/`enable_tracing`) and
-                    `MetricsRegistry` counters/gauges/histograms, wired
+                    `MetricsRegistry` counters and histograms, wired
                     through the tune/decompose/serve stack; traces export
                     to Perfetto (docs/observability.md).
 

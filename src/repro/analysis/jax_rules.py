@@ -446,15 +446,14 @@ def check_tracer_leak(ctx: FileContext) -> Iterator[Finding]:
 #: Observability entrypoints (repro.obs) that must never run under a trace:
 #: bare-name calls and attribute-call leaves, matched lexically.
 _OBS_NAME_CALLS = ("span", "record_span")
-_OBS_ATTR_CALLS = ("span", "record", "record_span", "observe", "inc",
-                   "set_value")
+_OBS_ATTR_CALLS = ("span", "record", "record_span", "observe", "inc")
 
 
 @register_rule(
     "trace-in-jit",
     packages=JAX_TARGETS,
     description=("a span or metric emission (`span(...)`, `record_span`, "
-                 "`.observe()`, `.inc()`, `.set_value()`, `tracer.record`) "
+                 "`.observe()`, `.inc()`, `tracer.record`) "
                  "inside the body of a jitted function"),
     rationale=("span/metric calls are host-side Python: under `jax.jit` "
                "they run once at trace time — recording bogus trace-time "

@@ -125,7 +125,8 @@ def avg_abs_diff(st: SparseTensor, factors, lam, *, dense_limit: int = 1 << 22) 
 def fit_value(st: SparseTensor, factors, lam, mlast=None, last_mode=None) -> float:
     """fit = 1 - ||X - X̂||_F / ||X||_F, using the standard sparse identity
     ||X - X̂||² = ||X||² - 2<X, X̂> + ||X̂||²."""
-    norm_x2 = st.norm() ** 2
+    with span("cp_als.fit_norm", nnz=int(st.nnz)):
+        norm_x2 = st.norm() ** 2
     grams = [jnp.matmul(jnp.asarray(f).T, jnp.asarray(f), precision=_HIGHEST)
              for f in factors]
     had = jnp.asarray(lam)[:, None] * jnp.asarray(lam)[None, :]
@@ -141,7 +142,8 @@ def fit_value(st: SparseTensor, factors, lam, mlast=None, last_mode=None) -> flo
     # Both reductions stay on device and fuse into ONE residual readout —
     # fit is a host scalar by contract, so exactly one sync is the floor
     # (this used to read norm_approx2 and inner back separately).
-    resid = max(float(norm_x2 - 2.0 * inner + norm_approx2), 0.0)
+    with span("cp_als.fit_readback"):
+        resid = max(float(norm_x2 - 2.0 * inner + norm_approx2), 0.0)
     return 1.0 - math.sqrt(resid) / max(math.sqrt(norm_x2), 1e-30)
 
 
@@ -276,28 +278,29 @@ def cp_als(
     policy = TunePolicy.resolve(tune, caller="cp_als", **legacy)
 
     n = st.ndim
-    factors = init_factors(st.shape, rank, seed)
-    lam = jnp.ones((rank,), jnp.float32)
-    if callable(engine):
-        if policy.accuracy_budget is not None:
-            raise ValueError(
-                "accuracy_budget only applies to engine='auto'; a prebuilt "
-                "engine has already made its format decision")
-        eng = engine
-        eng_name = getattr(engine, "name", None) or getattr(
-            engine, "__name__", "custom")
-    else:
-        from ..engine import build_engine
-        eng = build_engine(st, engine, rank, tune=policy, **engine_kwargs)
-        eng_name = eng.name  # e.g. "chunked", "auto:hetero"
-
-    fit_fast = _exact_mttkrp(eng)
     fit_history, diff_history, iter_times = [], [], []
     prev_fit = -np.inf
-    decompose_sp = span("cp_als.decompose", engine=eng_name,
-                        shape=list(st.shape), nnz=int(st.nnz), rank=rank,
-                        n_iters=n_iters)
+    decompose_sp = span("cp_als.decompose", shape=list(st.shape),
+                        nnz=int(st.nnz), rank=rank, n_iters=n_iters)
     with decompose_sp:
+        with span("cp_als.init", rank=rank, shape=list(st.shape)):
+            factors = init_factors(st.shape, rank, seed)
+            lam = jnp.ones((rank,), jnp.float32)
+        if callable(engine):
+            if policy.accuracy_budget is not None:
+                raise ValueError(
+                    "accuracy_budget only applies to engine='auto'; a prebuilt "
+                    "engine has already made its format decision")
+            eng = engine
+            eng_name = getattr(engine, "name", None) or getattr(
+                engine, "__name__", "custom")
+        else:
+            from ..engine import build_engine
+            eng = build_engine(st, engine, rank, tune=policy, **engine_kwargs)
+            eng_name = eng.name  # e.g. "chunked", "auto:hetero"
+        decompose_sp.set(engine=eng_name)
+
+        fit_fast = _exact_mttkrp(eng)
         for it in range(n_iters):
             iter_sp = span("cp_als.iter", iter=it)
             with iter_sp:
@@ -308,22 +311,25 @@ def cp_als(
                     # barrier sits at iteration end, so a mode span closing
                     # does not mean the mode's kernels finished.
                     with span("cp_als.mode", mode=mode):
-                        m = eng([jnp.asarray(f) for f in factors], mode)
-                        # Pseudo-inverse step:
-                        # A = M (∘_{k≠mode} F_kᵀF_k)†  (Alg. 1 l.5-7)
-                        v = jnp.ones((rank, rank), jnp.float32)
-                        for k in range(n):
-                            if k == mode:
-                                continue
-                            fk = jnp.asarray(factors[k])
-                            v = v * jnp.matmul(fk.T, fk, precision=_HIGHEST)
-                        a = jnp.matmul(m, jnp.linalg.pinv(v),
-                                       precision=_HIGHEST)
-                        a, lam = _normalize(a, norm)
-                        factors[mode] = a
+                        with span("cp_als.mttkrp", mode=mode):
+                            m = eng([jnp.asarray(f) for f in factors], mode)
+                        with span("cp_als.solve", mode=mode):
+                            # Pseudo-inverse step:
+                            # A = M (∘_{k≠mode} F_kᵀF_k)†  (Alg. 1 l.5-7)
+                            v = jnp.ones((rank, rank), jnp.float32)
+                            for k in range(n):
+                                if k == mode:
+                                    continue
+                                fk = jnp.asarray(factors[k])
+                                v = v * jnp.matmul(fk.T, fk, precision=_HIGHEST)
+                            a = jnp.matmul(m, jnp.linalg.pinv(v),
+                                           precision=_HIGHEST)
+                            a, lam = _normalize(a, norm)
+                            factors[mode] = a
                         mlast = m
-                # repro-lint: disable=host-sync -- timing barrier: iter_times must measure completed device work, not dispatch
-                jax.block_until_ready(factors[-1])
+                with span("cp_als.sync"):
+                    # repro-lint: disable=host-sync -- timing barrier: iter_times must measure completed device work, not dispatch
+                    jax.block_until_ready(factors[-1])
                 dt = time.perf_counter() - t0
                 # One measurement, two views: `iter_times` on the CPResult
                 # and the span's `seconds` attr carry the same number (the
@@ -348,9 +354,11 @@ def cp_als(
             prev_fit = f
         decompose_sp.set(fit=fit_history[-1] if fit_history else None)
 
-    return CPResult(
-        [np.asarray(f) for f in factors], np.asarray(lam),
-        fit_history, diff_history, iter_times, eng_name,
-        quant_error=_measured_quant_error(eng, st, factors),
-        tune_report=getattr(eng, "report", None),
-    )
+        with span("cp_als.readback"):
+            result = CPResult(
+                [np.asarray(f) for f in factors], np.asarray(lam),
+                fit_history, diff_history, iter_times, eng_name,
+                quant_error=_measured_quant_error(eng, st, factors),
+                tune_report=getattr(eng, "report", None),
+            )
+    return result

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 from collections.abc import Callable
 
+from ..obs.tracing import span
 from . import backends as _backends  # imported for side effect: registers the built-ins
 from .autotune import AutotuneReport, autotune_engine
 from .calibrate import (
@@ -178,29 +179,30 @@ def build_engine(
     if callable(method):
         return Engine(getattr(method, "__name__", "custom"), method)
 
-    ctx = EngineContext(
-        st=st, rank=rank,
-        plans=plans if plans is not None else default_plan_cache,
-        **options)
+    with span("engine.build", engine=method, nnz=int(st.nnz), rank=rank):
+        ctx = EngineContext(
+            st=st, rank=rank,
+            plans=plans if plans is not None else default_plan_cache,
+            **options)
 
-    if method == "auto":
-        handle, _report = autotune_engine(ctx, tune=policy,
-                                          modes=autotune_modes)
-        return handle
-    if policy.accuracy_budget is not None:
-        raise ValueError(
-            "accuracy_budget only applies to engine='auto' (an explicit "
-            f"backend — here {method!r} — is already a format decision); "
-            "drop the budget or switch to the autotuner")
-
-    name, preset = parse_candidate(method)
-    spec = get_backend(name)
-    if preset is not None:
-        explicit = options.get("fixed_preset")
-        if explicit is not None and explicit != preset:
+        if method == "auto":
+            handle, _report = autotune_engine(ctx, tune=policy,
+                                              modes=autotune_modes)
+            return handle
+        if policy.accuracy_budget is not None:
             raise ValueError(
-                f"conflicting presets: method {method!r} pins "
-                f"{preset!r} but fixed_preset={explicit!r} was also passed; "
-                "drop one of the two spellings")
-        ctx.fixed_preset = preset
-    return Engine(method, spec.build(ctx), spec=spec, context=ctx)
+                "accuracy_budget only applies to engine='auto' (an explicit "
+                f"backend — here {method!r} — is already a format decision); "
+                "drop the budget or switch to the autotuner")
+
+        name, preset = parse_candidate(method)
+        spec = get_backend(name)
+        if preset is not None:
+            explicit = options.get("fixed_preset")
+            if explicit is not None and explicit != preset:
+                raise ValueError(
+                    f"conflicting presets: method {method!r} pins "
+                    f"{preset!r} but fixed_preset={explicit!r} was also passed; "
+                    "drop one of the two spellings")
+            ctx.fixed_preset = preset
+        return Engine(method, spec.build(ctx), spec=spec, context=ctx)

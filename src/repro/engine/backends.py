@@ -31,6 +31,8 @@ from ..core.distributed import DistributedMTTKRP
 from ..core.partition import decide_kernel_partition
 from ..core.qformat import FIXED_PRESETS, value_qformat
 from ..launch.mesh import make_local_mesh
+from ..obs.metrics import default_registry
+from ..obs.tracing import span
 from .registry import EngineContext, register_backend
 
 __all__ = []  # backends are reached through the registry, not by import
@@ -183,7 +185,14 @@ def _build_pallas(ctx: EngineContext):
     plan_cs, plan_cap = decide_kernel_partition(shape, ctx.st.nnz)
     cs = ctx.chunk_shape or plan_cs
     cap = ctx.capacity if ctx.chunk_shape or ctx.capacity else plan_cap
-    kt = kops.kernel_tensor(ctx.plans.chunked(ctx.st, cs, cap))
+    ct = ctx.plans.chunked(ctx.st, cs, cap)
+    with span("layout.kernel") as sp:
+        kt = kops.kernel_tensor(ct)
+        tasks, _, slots = kt.values.shape
+        sp.set(nnz=ctx.st.nnz, tasks=tasks, slots_per_task=slots, calls=kt.calls)
+    # The layout's slot fill, nnz / (T·P), counted whether or not tracing is on.
+    default_registry.counter("layout.kernel_nonzeros").inc(ctx.st.nnz)
+    default_registry.counter("layout.kernel_slots").inc(tasks * slots)
     interpret = ctx.interpret
 
     def engine(factors, mode):

@@ -14,11 +14,14 @@ garbage collected, so the cache never outlives its tensors.
 from __future__ import annotations
 
 import dataclasses
+import time
 import weakref
 
 from ..core.chunking import ChunkedTensor, chunk_tensor, clamp_capacity
 from ..core.partition import PartitionPlan, decide_partition
 from ..core.sptensor import SparseTensor
+from ..obs.metrics import default_registry
+from ..obs.tracing import span
 
 __all__ = ["PlanCache", "CacheStats", "default_plan_cache"]
 
@@ -88,7 +91,14 @@ class PlanCache:
             self.stats.chunk_hits += 1
         else:
             self.stats.chunk_misses += 1
-            self._chunked[k] = chunk_tensor(st, tuple(chunk_shape), capacity)
+            with span("layout.chunk", chunk_shape=list(chunk_shape)) as sp:
+                # Timed whether or not tracing is on.
+                t0 = time.perf_counter()
+                ct = chunk_tensor(st, tuple(chunk_shape), capacity)
+                default_registry.histogram("layout.chunk_seconds").observe(
+                    time.perf_counter() - t0)
+                sp.set(tasks=ct.num_tasks, capacity=ct.capacity)
+            self._chunked[k] = ct
         return self._chunked[k]
 
     def device_arrays(self, st: SparseTensor, chunk_shape: tuple[int, ...],

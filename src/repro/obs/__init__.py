@@ -7,7 +7,7 @@ inventory, and the Perfetto how-to):
   no-op when disabled (one attribute check on the hot path).  Enable with
   `enable_tracing()`, the `capture()` scope, or ``REPRO_TRACE=1`` /
   ``REPRO_TRACE_PATH=trace.jsonl`` in the environment.
-- `metrics` — counters/gauges/histograms; histograms use fixed log-spaced
+- `metrics` — counters and histograms; histograms use fixed log-spaced
   buckets so p50/p95/p99 come without storing samples, and registry
   snapshots are consistent cuts.
 - `export` — trace JSONL read/write, Chrome trace-event JSON for Perfetto,
@@ -37,7 +37,6 @@ from .export import (
 )
 from .metrics import (
     Counter,
-    Gauge,
     Histogram,
     MetricsRegistry,
     default_histogram_bounds,
@@ -62,7 +61,6 @@ __all__ = [
     "TRACE_ENV",
     "TRACE_PATH_ENV",
     "Counter",
-    "Gauge",
     "Histogram",
     "MetricsRegistry",
     "SpanRecord",

@@ -1,8 +1,8 @@
-"""Counters, gauges, and log-bucketed histograms with consistent snapshots.
+"""Counters and log-bucketed histograms with consistent snapshots.
 
 The paper reports efficiency as a fraction of peak measured over whole
 workloads; the serving path needs the same kind of aggregate — request
-latency p50/p99, queue depth, probe counts — without keeping every sample.
+latency p50/p99, probe counts — without keeping every sample.
 `Histogram` therefore bins observations into **fixed log-spaced buckets**
 (8 per decade from 1µs to 1000s by default): percentiles come from the
 cumulative bucket counts with log-linear interpolation inside the landing
@@ -24,7 +24,6 @@ import threading
 
 __all__ = [
     "Counter",
-    "Gauge",
     "Histogram",
     "MetricsRegistry",
     "default_histogram_bounds",
@@ -63,31 +62,6 @@ class Counter:
 
     def _snapshot(self) -> dict:
         return {"type": "counter", "value": self._value}
-
-
-class Gauge:
-    """Last-write-wins instantaneous value (queue depth, live buckets)."""
-
-    def __init__(self, name: str, lock: threading.Lock):
-        self.name = name
-        self._lock = lock
-        self._value = 0.0
-
-    def set_value(self, v: float) -> None:
-        with self._lock:
-            self._value = float(v)
-
-    def add(self, delta: float) -> None:
-        with self._lock:
-            self._value += delta
-
-    @property
-    def value(self) -> float:
-        with self._lock:
-            return self._value
-
-    def _snapshot(self) -> dict:
-        return {"type": "gauge", "value": self._value}
 
 
 class Histogram:
@@ -204,7 +178,7 @@ class MetricsRegistry:
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._metrics: dict[str, Counter | Gauge | Histogram] = {}
+        self._metrics: dict[str, Counter | Histogram] = {}
 
     def _get_or_create(self, name: str, kind, **kwargs):
         with self._lock:
@@ -222,9 +196,6 @@ class MetricsRegistry:
 
     def counter(self, name: str) -> Counter:
         return self._get_or_create(name, Counter)
-
-    def gauge(self, name: str) -> Gauge:
-        return self._get_or_create(name, Gauge)
 
     def histogram(self, name: str,
                   bounds: tuple[float, ...] | None = None) -> Histogram:

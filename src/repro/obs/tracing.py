@@ -22,6 +22,12 @@ The contract that keeps this safe to leave in the hot paths:
   from the tracer's epoch; one wall-clock anchor (`epoch_wall`) taken at
   enable time lets the exporter place the trace in absolute time without
   wall clocks ever steering a measurement.
+- **On the profiler's clock.**  An enabled `span` (and `@traced`) also
+  enters a `jax.profiler.TraceAnnotation` of the same name, so under
+  `jax.profiler.trace` every span appears on the profiler's host plane,
+  on the device trace's clock.  `jax` is imported there, on the enabled
+  path only.  `record_span` records a region after the fact, which no
+  annotation can cover: its records stay off the profiler's clock.
 
 Enable programmatically (`enable_tracing()` / the `capture()` context
 manager) or by environment: ``REPRO_TRACE=1`` turns the tracer on at
@@ -130,8 +136,8 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """An open span (the enabled path of `span(...)`)."""
 
-    __slots__ = ("_attrs", "_name", "_t0", "_tracer", "duration",
-                 "parent_id", "span_id")
+    __slots__ = ("_annotation", "_attrs", "_name", "_t0", "_tracer",
+                 "duration", "parent_id", "span_id")
 
     def __init__(self, tracer: Tracer, name: str, attrs: dict):
         self._tracer = tracer
@@ -140,6 +146,9 @@ class _Span:
         self.duration: float | None = None
 
     def __enter__(self) -> _Span:
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
         tr = self._tracer
         stack = tr._stack()
         self.parent_id = stack[-1] if stack else 0
@@ -150,6 +159,7 @@ class _Span:
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
         tr = self._tracer
         stack = tr._stack()
         if stack and stack[-1] == self.span_id:
