@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.metrics import default_registry
 from ..obs.tracing import span
 from .sptensor import SparseTensor
 
@@ -124,9 +125,17 @@ def avg_abs_diff(st: SparseTensor, factors, lam, *, dense_limit: int = 1 << 22) 
 
 def fit_value(st: SparseTensor, factors, lam, mlast=None, last_mode=None) -> float:
     """fit = 1 - ||X - X̂||_F / ||X||_F, using the standard sparse identity
-    ||X - X̂||² = ||X||² - 2<X, X̂> + ||X̂||²."""
-    with span("cp_als.fit_norm", nnz=int(st.nnz)):
-        norm_x2 = st.norm() ** 2
+    ||X - X̂||² = ||X||² - 2<X, X̂> + ||X̂||².
+
+    ||X|| is computed on the tensor's first fit and kept on the tensor
+    (`SparseTensor.norm`); every later fit of it reads the same float."""
+    if st.norm_known:
+        default_registry.counter("cp_als.fit_norm_reused").inc()
+    else:
+        with span("cp_als.fit_norm", nnz=int(st.nnz)):
+            st.norm()
+        default_registry.counter("cp_als.fit_norm_computed").inc()
+    norm_x2 = st.norm() ** 2
     grams = [jnp.matmul(jnp.asarray(f).T, jnp.asarray(f), precision=_HIGHEST)
              for f in factors]
     had = jnp.asarray(lam)[:, None] * jnp.asarray(lam)[None, :]

@@ -8,6 +8,7 @@ match each Table-I entry, scaled to CPU-runnable sizes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import numpy as np
@@ -51,6 +52,18 @@ class SparseTensor:
         return out.astype(np.float32)
 
     def norm(self) -> float:
+        """‖X‖_F of the nonzeros, in float64.  The first call computes it and
+        the tensor keeps it: the fields are frozen and no code writes into
+        their arrays, so every later call returns the same float."""
+        return self._norm
+
+    @property
+    def norm_known(self) -> bool:
+        """True once `norm()` has run on this tensor, so that a call is free."""
+        return "_norm" in self.__dict__
+
+    @functools.cached_property
+    def _norm(self) -> float:
         return float(np.linalg.norm(self.values.astype(np.float64)))
 
     def permuted(self, order: np.ndarray) -> SparseTensor:
